@@ -43,6 +43,8 @@ def main():
                     help="write per-problem rows (incl. audit_seconds, "
                          "n_pruned_static) as JSON")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     logging.basicConfig(level=logging.INFO,
                         format="%(name)s: %(message)s")
     cache = args.cache or os.path.join(tempfile.mkdtemp(), "plans.json")

@@ -638,6 +638,8 @@ def main() -> None:
                          "exits nonzero unless resident == roundtrip "
                          "and overlapped == serialized bitwise")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     if args.distributed:
         payload = distributed()
         if not payload["distributed"]["parity"]:

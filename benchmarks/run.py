@@ -25,6 +25,8 @@ def main() -> None:
                     help="all stencils / all archs (slower)")
     ap.add_argument("--only", choices=SECTIONS, default=None)
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
 
     from benchmarks import (bench_kernels, bench_models, bench_schemes,
                             bench_sweep, bench_tiling)

@@ -13,12 +13,14 @@ sys.path.insert(0, "src")
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import stencils, tessellate
 
 N, STEPS, H = 256, 128, 4
 
 
 def main():
+    compile_cache.enable()
     spec = stencils.make("heat2d")
     x = jnp.zeros((N, N), jnp.float32)
     x = x.at[120:136, 120:136].set(100.0)     # hot square (16×16:

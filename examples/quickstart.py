@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import stencils, tessellate, vectorize
 from repro.core.api import StencilPlan, StencilProblem
 from repro.kernels import ops, ref
@@ -26,6 +27,7 @@ N, STEPS = 1 << 20, 8
 
 
 def main():
+    compile_cache.enable()
     prob = StencilProblem("1d3p", (N,))
     x = prob.init(seed=0)
     oracle = prob.reference(x, STEPS)

@@ -35,7 +35,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis import jaxpr_audit
 
@@ -54,15 +54,21 @@ class BlockSpecFinding:
 
 def _eval_index_map(im, point) -> tuple[int, ...]:
     return tuple(int(v) for v in
-                 jcore.eval_jaxpr(im.jaxpr, im.consts, *point))
+                 jcore.jaxpr_as_fun(im)(*point))
+
+
+def _block_size(blk) -> int:
+    """Extent of one block dim: a plain int, a ``Blocked``-style dim
+    carrying ``block_size``, or 1 for a squeezed/mapped dim."""
+    if isinstance(blk, int):
+        return blk
+    size = getattr(blk, "block_size", None)
+    return size if isinstance(size, int) else 1
 
 
 def _nblocks(arr_shape, block_shape) -> tuple[int, ...]:
-    out = []
-    for dim, blk in zip(arr_shape, block_shape):
-        b = blk if isinstance(blk, int) else 1      # mapped dims: size 1
-        out.append(-(-int(dim) // max(b, 1)))
-    return tuple(out)
+    return tuple(-(-int(dim) // max(_block_size(blk), 1))
+                 for dim, blk in zip(arr_shape, block_shape))
 
 
 def _enumerate(bm, grid, points):
@@ -71,7 +77,7 @@ def _enumerate(bm, grid, points):
     im = bm.index_map_jaxpr
     if len(im.jaxpr.invars) != len(grid):
         return None, None
-    nb = _nblocks(tuple(bm.array_shape_dtype.shape),
+    nb = _nblocks(tuple(bm.array_aval.shape),
                   tuple(bm.block_shape))
     return {pt: _eval_index_map(im, pt) for pt in points}, nb
 

@@ -33,7 +33,7 @@ import dataclasses
 from collections import Counter
 
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 #: control-flow primitives whose bodies are "the sweep loop" for the
 #: resident-layout census (matches the historical test walkers)

@@ -1,14 +1,7 @@
-"""Version compatibility shims for the jax API surface we depend on.
+"""Thin wrappers over the jax API surface the repo calls in many places.
 
-Two call sites drift across jax releases:
-
-* ``shard_map`` — promoted from ``jax.experimental.shard_map`` (keyword
-  ``check_rep``) to ``jax.shard_map`` (keyword ``check_vma``).
-* ``Compiled.cost_analysis()`` — older jaxlibs return a one-element list of
-  per-program dicts, newer ones a flat dict.
-
-Everything else in the repo calls through here so the version split lives
-in exactly one file.
+* ``shard_map`` — ``jax.shard_map`` with replication checking off.
+* ``Compiled.cost_analysis()`` — normalized to a flat dict.
 """
 from __future__ import annotations
 
@@ -18,19 +11,11 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, any jax version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def cost_analysis_dict(cost: Any) -> dict:
     """Normalize ``compiled.cost_analysis()`` to a flat {metric: value} dict."""
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    return dict(cost) if cost else {}

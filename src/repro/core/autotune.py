@@ -29,8 +29,8 @@ over **every execution backend at once**:
      resident-sweep candidate (single-device AND distributed)
      additionally fans out over the temporal-tile axis ``ttile`` ∈
      :data:`_TTILES`, gated by :func:`ttile_plan_legal` (halo slope
-     fits the local extent, VMEM window fits
-     :data:`TTILE_VMEM_BUDGET`, the run is deep enough to amortize).  Off-TPU the
+     fits the local extent, the kernel fits the chip's VMEM, the run is
+     deep enough to amortize).  Off-TPU the
      auto pool caps pallas enumeration at
      :data:`INTERPRET_MAX_POINTS` grid points (interpret-mode
      measurement latency budget; explicit ``backend="pallas"`` /
@@ -105,12 +105,15 @@ Plan-cache file format (JSON, ``REPRO_PLAN_CACHE`` env var or
          "seconds_per_step": 1.2e-4,
          "fingerprint": "3f2a9c1d04be",
          "n_candidates": 23, "n_measured": 8,
-         "measurements": [{"plan": {...}, "seconds_per_step": ...}, ...]
+         "measurements": [{"plan": {...}, "seconds_per_step": ...}, ...],
+         "failures": [{"plan": {...}, "error": "..."}, ...]
        }}}
 
 ``measurements`` is the tuning log: one row per measured candidate, in
-measurement order.  Corrupt or version-mismatched files are ignored (the
-tuner re-measures and overwrites).
+measurement order.  ``failures`` lists the candidates that raised while
+being measured (on the chip: a kernel Mosaic refused).  Corrupt or
+version-mismatched files are ignored (the tuner re-measures and
+overwrites).
 """
 from __future__ import annotations
 
@@ -163,15 +166,6 @@ INTERPRET_PENALTY = 50.0
 # backend="pallas" request bypasses the gate).  Env-overridable.
 INTERPRET_MAX_POINTS = int(os.environ.get(
     "REPRO_PALLAS_INTERPRET_MAX_POINTS", 1 << 18))
-
-# VMEM budget for the temporal-tile scratch window: a depth-d launch keeps
-# d live blocks + d carry rows resident per grid step (see
-# kernels/stencil_kernels), and TPU cores have ~16 MB of VMEM shared with
-# the in/out block pipeline — candidates whose window exceeds this budget
-# are rejected by :func:`ttile_plan_legal`.  Env-overridable.
-TTILE_VMEM_BUDGET = int(os.environ.get(
-    "REPRO_TTILE_VMEM_BUDGET", 4 << 20))
-
 
 def default_cache_path() -> str:
     env = os.environ.get(CACHE_ENV)
@@ -471,6 +465,8 @@ def pallas_plan_legal(spec: stencils.StencilSpec, shape: Sequence[int],
       is what rejects non-dividing combinations);
     * halo-fits-block: ``r <= m`` and ``r <= vl`` (the kernels assemble
       at most r boundary rows per vector set, and carry r lanes);
+    * lanes (n-D, compiled by Mosaic): ``vl`` a multiple of 128
+      (:func:`_lanes_compile`);
     * pipeline tile (n-D only): ``t0`` must divide ``shape[0]`` and hold
       the halo (``t0 >= r``);
     * sweep engine: ``resident`` (layout-resident wrapped-grid sweeps) or
@@ -481,13 +477,15 @@ def pallas_plan_legal(spec: stencils.StencilSpec, shape: Sequence[int],
     * temporal tile: ``ttile > 1`` requires the resident engine (the
       roundtrip path re-lays-out every sweep — there is nothing to
       temporally tile) and is further gated by :func:`ttile_plan_legal`
-      (slope fits the extent, VMEM window fits the budget);
+      (slope fits the extent, kernel fits VMEM);
     * schedule depth (only checked when ``k``/``steps`` are given): the
       deepest launch of the (k, steps, remainder, ttile) schedule —
       including a ``remainder="native"`` block of ``steps % k`` steps —
-      must keep its halo slope ``depth·r`` within the pipelined extent.
+      must keep its halo slope ``depth·r`` within the pipelined extent,
+      and its kernel must fit the chip's VMEM (:func:`_vmem_fits`).
       This is what rejects native-remainder plans whose leftover block
-      is too deep for the grid instead of letting them fail at run time.
+      is too deep for the grid, and tiles Mosaic would refuse, instead
+      of letting them fail at run time.
     """
     if sweep not in ("resident", "roundtrip"):
         return False
@@ -495,7 +493,7 @@ def pallas_plan_legal(spec: stencils.StencilSpec, shape: Sequence[int],
         return False
     n = shape[-1]
     r = spec.r
-    if n % (vl * m) or m < r or vl < r:
+    if n % (vl * m) or m < r or vl < r or not _lanes_compile(spec, vl):
         return False
     if spec.ndim > 1:
         if t0 is None or t0 < r or shape[0] % t0:
@@ -505,15 +503,67 @@ def pallas_plan_legal(spec: stencils.StencilSpec, shape: Sequence[int],
         n_pipe = shape[0] if spec.ndim > 1 else n
         if kmax * r > n_pipe:
             return False
+        if not _vmem_fits(spec, shape, kmax, vl, m, t0):
+            return False
     return True
 
 
-def _pallas_pairs(n: int, r: int) -> list[tuple[int, int]]:
+def _lanes_compile(spec: stencils.StencilSpec, vl: int) -> bool:
+    """Mosaic merges the (nb, vl) lane axes of an n-D tile into one lane
+    row (``vectorize.extend_vs``), which it lowers for whole 128-lane
+    vregs only; the interpreter takes any width."""
+    from repro.kernels import ops, stencil_kernels as sk
+    return spec.ndim == 1 or vl % sk.DEFAULT_VL == 0 \
+        or ops._auto_interpret(None)
+
+
+def _vmem_fits(spec: stencils.StencilSpec, local: Sequence[int],
+               depth: int, vl: int, m: int, t0: int | None,
+               itemsize: int = 4) -> bool:
+    """Does a depth-``depth`` sweep kernel over the (local) extents fit
+    the VMEM a kernel may claim (``stencil_kernels.sweep_vmem_bytes``)?
+    The f32 default bounds bf16 too: its tiles pad to no more bytes."""
+    from repro.kernels import stencil_kernels as sk
+    if spec.ndim == 1:
+        block = (1, m, vl)
+    else:
+        block = (t0 or 1,) + tuple(local[1:-1]) \
+            + (local[-1] // (vl * m), m, vl)
+    return sk.sweep_vmem_bytes(spec, block, depth, itemsize) \
+        <= sk.VMEM_LIMIT_BYTES
+
+
+def _pipeline_tiles(spec: stencils.StencilSpec, n0: int,
+                    row: int) -> list[int]:
+    """Pipeline tiles ``t0`` the n-D pools enumerate (largest first):
+    powers of two up to 8 that divide ``n0`` and hold the halo, keeping
+    the tile within ``ND_TILE_ELEMS`` (``row`` elements per t0-row) —
+    or, when every such tile is larger, the default tiler's pick."""
+    from repro.kernels import ops, stencil_kernels as sk
+    t0s = [t for t in (8, 4, 2) if t <= n0 and n0 % t == 0 and t >= spec.r]
+    small = [t for t in t0s if t * row <= sk.ND_TILE_ELEMS]
+    if small:
+        return small
+    t0 = ops.pipeline_tile(n0, row, spec.r)
+    return [t0] if t0 else []
+
+
+def _kernel_lanes() -> tuple[int, ...]:
+    """Lane widths of the Pallas pools: the chip's native 128 lanes where
+    Mosaic compiles the kernels (:func:`pallas_plan_legal`); the narrow
+    widths interpret-mode tuning can afford elsewhere."""
+    from repro.kernels import ops, stencil_kernels as sk
+    return _VLS if ops._auto_interpret(None) else (sk.DEFAULT_VL,)
+
+
+def _pallas_pairs(n: int, r: int,
+                  lanes: Sequence[int] = _VLS) -> list[tuple[int, int]]:
     """(vl, m) pairs for the Pallas backend: m ranges over divisors of
     n/vl (so non-power-of-two vl·m blocks are reachable when the extent
     calls for them), capped at ``_MAX_M_PER_VL`` per vl."""
+    from repro.kernels import stencil_kernels as sk
     pairs = []
-    for vl in _VLS:
+    for vl in lanes:
         if vl < r or n % vl:
             continue
         q = n // vl
@@ -521,7 +571,8 @@ def _pallas_pairs(n: int, r: int) -> list[tuple[int, int]]:
                     if q % m == 0]
         # prefer the square-ish tiles the paper favors, then fill with the
         # remaining (possibly non-power-of-two) divisors
-        keep = [m for m in (vl, vl // 2, 2 * vl) if m in divisors]
+        keep = [m for m in dict.fromkeys((vl, vl // 2, 2 * vl, sk.DEFAULT_M))
+                if m in divisors]
         for m in divisors:
             if len(keep) >= _MAX_M_PER_VL:
                 break
@@ -576,6 +627,7 @@ def distributed_plan_legal(spec: stencils.StencilSpec,
       ghost codec (``halo.exchange_minor``) — the per-axis halo-fits
       checks above already guarantee every whole-unit rounding fits the
       shard (the exchanged width rounds up within a divisible extent).
+      The local sweep kernel must fit the chip's VMEM (:func:`_vmem_fits`).
       The ``sweep`` axis (resident | roundtrip) is validated here and
       interchangeable wherever the engine is legal (both exchange the
       same valid ghost cells).
@@ -609,9 +661,11 @@ def distributed_plan_legal(spec: stencils.StencilSpec,
     if engine != "pallas" or sweep not in ("resident", "roundtrip"):
         return False
     n_minor = local[-1]
-    if vl < r or m < r or n_minor % (vl * m):
+    if vl < r or m < r or n_minor % (vl * m) or not _lanes_compile(spec, vl):
         return False
     if spec.ndim > 1 and (t0 is None or t0 < r or local[0] % t0):
+        return False
+    if not _vmem_fits(spec, local, kmax, vl, m, t0):
         return False
     if overlap:
         # interior/boundary overlap rides the axis-0 ring (n-D) or the
@@ -722,24 +776,6 @@ def _mxu_candidates(spec: stencils.StencilSpec, shape: tuple[int, ...],
     return cands
 
 
-def _ttile_window_bytes(spec: stencils.StencilSpec,
-                        local: Sequence[int], depth: int, vl: int, m: int,
-                        t0: int | None, itemsize: int = 4) -> int:
-    """VMEM bytes the resident kernels keep live for a depth-``depth``
-    launch: the (depth, block) sliding window plus the (depth, r, lanes)
-    boundary carries (see ``kernels/stencil_kernels`` scratch shapes)."""
-    r = spec.r
-    if spec.ndim == 1:
-        window = depth * m * vl
-        carry = depth * r * vl
-    else:
-        mid = int(np.prod(local[1:-1])) if spec.ndim > 2 else 1
-        block = (t0 or 1) * mid * local[-1]
-        window = depth * block
-        carry = depth * r * mid * local[-1]
-    return (window + carry) * itemsize
-
-
 def ttile_plan_legal(spec: stencils.StencilSpec, shape: Sequence[int],
                      plan: StencilPlan, steps: int | None = None,
                      itemsize: int = 4,
@@ -761,10 +797,9 @@ def ttile_plan_legal(spec: stencils.StencilSpec, shape: Sequence[int],
     * steps-amortizable: with ``steps`` given, at least one full
       ``ttile·k`` block must execute (``steps // k >= ttile``) — deeper
       tiles than the run are wasted redundant compute;
-    * VMEM window: the kernel's live scratch
-      (:func:`_ttile_window_bytes`) must fit
-      :data:`TTILE_VMEM_BUDGET` — deep tiles on fat blocks would spill
-      the very window residency the schedule exists to exploit.
+    * VMEM: the depth-``d`` kernel must fit the VMEM a kernel may
+      claim (:func:`_vmem_fits`) — Mosaic refuses deep tiles on fat
+      blocks at compile time.
     """
     tt = plan.ttile
     if tt < 1:
@@ -817,8 +852,7 @@ def ttile_plan_legal(spec: stencils.StencilSpec, shape: Sequence[int],
     if uses_pallas:
         vl = plan.vl if plan.m is not None else 8
         m = plan.m if plan.m is not None else 8
-        if _ttile_window_bytes(spec, local, depth, vl, m, plan.t0,
-                               itemsize) > TTILE_VMEM_BUDGET:
+        if not _vmem_fits(spec, local, depth, vl, m, plan.t0, itemsize):
             return False
     return True
 
@@ -897,10 +931,11 @@ def _distributed_candidates(spec: stencils.StencilSpec,
             if spec.ndim == 1:
                 t0s: list[int | None] = [None]
             else:
-                nl0 = shape[0] // decomp[0]
-                t0s = [t for t in (8, 4, 2)
-                       if t <= nl0 and nl0 % t == 0 and t >= spec.r][:1]
-            for vl, m in _pallas_pairs(n_minor, spec.r)[:2]:
+                local = [n // s for n, s in zip(shape, decomp)]
+                t0s = _pipeline_tiles(spec, local[0],
+                                      int(np.prod(local[1:])))[:1]
+            for vl, m in _pallas_pairs(n_minor, spec.r,
+                                       _kernel_lanes())[:2]:
                 for t0 in t0s:
                     for swp in ("resident", "roundtrip"):
                         base = StencilPlan(
@@ -941,9 +976,8 @@ def _pallas_candidates(spec: stencils.StencilSpec, shape: tuple[int, ...],
     if spec.ndim == 1:
         t0s: list[int | None] = [None]
     else:
-        t0s = [t for t in (8, 4, 2)
-               if t <= n0 and n0 % t == 0 and t >= spec.r][:_MAX_T0]
-    for vl, m in _pallas_pairs(shape[-1], spec.r):
+        t0s = _pipeline_tiles(spec, n0, int(np.prod(shape[1:])))[:_MAX_T0]
+    for vl, m in _pallas_pairs(shape[-1], spec.r, _kernel_lanes()):
         for t0 in t0s:
             for sweep in ("resident", "roundtrip"):
                 if not pallas_plan_legal(spec, shape, vl, m, t0, sweep):
@@ -1048,6 +1082,7 @@ class TuneResult:
     n_pruned_static: int = 0           # survivors the static audit rejected
     audit_seconds: float = 0.0         # wall time spent auditing survivors
     pruned: list = dataclasses.field(default_factory=list)  # [(plan, names)]
+    failures: list = dataclasses.field(default_factory=list)  # [(plan, err)]
 
 
 def _default_timer(fn: Callable[[], jax.Array], plan: StencilPlan) -> float:
@@ -1191,14 +1226,15 @@ def tune(problem, backend: str = "auto", steps: int | None = None,
             + "; ".join(f"{p}: {', '.join(sorted(set(n)))}"
                         for p, n in pruned))
     x = problem.init(seed=0)
-    measurements = []
+    measurements, failures = [], []
     best_plan, best_t = None, float("inf")
     for plan in survivors:
         fn = lambda p=plan: problem.run(x, measure_steps, p)
         try:
             t = float(timer(fn, plan)) / measure_steps
-        except Exception as e:   # a candidate that fails to run is skipped
+        except Exception as e:   # recorded, and the search goes on
             logger.warning("candidate %s failed: %s", plan, e)
+            failures.append((plan, f"{type(e).__name__}: {e}"))
             continue
         measurements.append({"plan": plan_to_dict(plan),
                              "seconds_per_step": t})
@@ -1206,7 +1242,8 @@ def tune(problem, backend: str = "auto", steps: int | None = None,
         if t < best_t:
             best_plan, best_t = plan, t
     if best_plan is None:
-        raise RuntimeError(f"every candidate failed for {key}")
+        raise RuntimeError(f"every candidate failed for {key}: "
+                           + "; ".join(f"{p}: {e}" for p, e in failures))
 
     # feed the roofline calibrator: every measured (modeled-terms, wall
     # time) pair tightens the per-device-kind throughput peaks — the max
@@ -1247,6 +1284,8 @@ def tune(problem, backend: str = "auto", steps: int | None = None,
               "audit_seconds": audit_seconds,
               "pruned": [{"plan": plan_to_dict(p),
                           "violations": sorted(set(n))} for p, n in pruned],
+              "failures": [{"plan": plan_to_dict(p), "error": e}
+                           for p, e in failures],
               "measurements": measurements}
     cache.put(key, record)
     cache.save()
@@ -1260,7 +1299,7 @@ def tune(problem, backend: str = "auto", steps: int | None = None,
                       measurements=measurements,
                       n_pruned_static=len(pruned),
                       audit_seconds=audit_seconds,
-                      pruned=list(pruned))
+                      pruned=list(pruned), failures=failures)
 
 
 def best_plan(problem, backend: str = "auto", steps: int | None = None,

@@ -179,6 +179,12 @@ def accum_dtype(dtype) -> jnp.dtype:
         else jnp.dtype(jnp.float32)
 
 
+def contraction_precision(dtype) -> lax.Precision | None:
+    """MXU pass rule: bf16 operands are exact in one pass; wider operands
+    ask for ``HIGHEST``, since the TPU's default rounds them to bf16."""
+    return None if jnp.dtype(dtype).itemsize <= 2 else lax.Precision.HIGHEST
+
+
 def apply_banded(op: BandedOperator, t, lead_halo=None, block_halo: int = 0):
     """Advance the resident layout ``t`` by ``op.depth`` steps with ONE
     ``dot_general``.
@@ -222,6 +228,7 @@ def apply_banded(op: BandedOperator, t, lead_halo=None, block_halo: int = 0):
     table = jnp.asarray(op.table.astype(t.dtype))
     acc = lax.dot_general(
         x, table, (((x.ndim - 1,), (0,)), ((), ())),
+        precision=contraction_precision(t.dtype),
         preferred_element_type=accum_dtype(t.dtype))
     out = acc.astype(t.dtype)
     return out.reshape(out.shape[:-1] + (op.m, op.vl))

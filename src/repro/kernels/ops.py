@@ -25,6 +25,7 @@ compile via Mosaic.  ``interpret=None`` auto-detects.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -51,8 +52,9 @@ def _fit_m(n_minor: int, vl: int, r: int, m: int | None) -> int | None:
 
 def pick_tile(spec: StencilSpec, shape, vl: int | None = None,
               m: int | None = None, t0: int | None = None):
-    """TPU-native defaults: vl=128 lanes, m=8 sublanes, pipeline tile t0=8;
-    shrink for small/test shapes while keeping divisibility.
+    """TPU-native defaults: vl=128 lanes, m=8 sublanes, pipeline tile t0 from
+    :func:`pipeline_tile`; shrink for small/test shapes while keeping
+    divisibility.
 
     When no legal ``m >= spec.r`` exists for the (default) vl — e.g. a
     1d5p stencil on shape (8,), where vl=8 only leaves m=1 < r — the vl is
@@ -82,14 +84,27 @@ def pick_tile(spec: StencilSpec, shape, vl: int | None = None,
     if len(shape) == 1:
         return vl, m, None
     n0 = shape[0]
-    t0 = t0 or min(8, n0)
-    while n0 % t0:
-        t0 -= 1
+    if t0 is None:
+        t0 = pipeline_tile(n0, math.prod(shape[1:]), r)
+    else:
+        while n0 % t0:
+            t0 -= 1
     if t0 < r:
         raise ValueError(
             f"no legal pipeline tile for stencil {spec.name!r} on shape "
             f"{tuple(shape)}: need t0 >= r={r} dividing n0={n0}")
     return vl, m, t0
+
+
+def pipeline_tile(n0: int, row: int, r: int) -> int:
+    """Default n-D pipeline tile over ``n0`` rows of ``row`` elements:
+    the largest ``t0 <= 8`` dividing ``n0`` whose tile stays within
+    ``ND_TILE_ELEMS`` elements, or the smallest ``t0 >= r`` dividing it
+    when every tile is larger (0 when none divides)."""
+    legal = [t for t in range(min(8, n0), max(r, 1) - 1, -1)
+             if n0 % t == 0]
+    small = [t for t in legal if t * row <= sk.ND_TILE_ELEMS]
+    return (small or legal[-1:] or [0])[0]
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2, 3, 4, 5, 6))

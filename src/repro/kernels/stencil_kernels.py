@@ -83,6 +83,15 @@ from repro.core.vectorize import extend_vs
 
 DEFAULT_VL = 128   # TPU lane count
 DEFAULT_M = 8      # TPU sublane count (f32)
+#: VMEM a kernel may claim: v5e has 128 MiB per core, and Mosaic keeps
+#: the rest for its own scratch.  Plans whose ``sweep_vmem_bytes`` exceed it
+#: are illegal (``core.autotune.pallas_plan_legal``).
+VMEM_LIMIT_BYTES = 100 << 20
+SCOPED_VMEM_DEFAULT = 16 << 20   # Mosaic's limit when a kernel sets none
+#: largest pipeline tile (t0·mid·minor elements) the n-D tilers pick when
+#: a smaller t0 is legal: the kernel body unrolls over the whole tile, so
+#: Mosaic's VMEM use and compile time grow with it
+ND_TILE_ELEMS = 1 << 16
 
 
 def _ring_masks_np(vl: int, m: int, r: int):
@@ -95,6 +104,47 @@ def _ring_masks_np(vl: int, m: int, r: int):
         le = vl * m - 1 - e
         lm[le % m, le // m] = True
     return fm, lm
+
+
+def _padded_bytes(shape, itemsize: int) -> int:
+    """VMEM bytes of an array: the minor dim pads to 128 lanes, the
+    second-minor to the sublane tile (Mosaic gives a 1-row second-minor
+    a 1-row tile)."""
+    *lead, sub, lane = shape
+    sub_tile = 1 if sub == 1 else 32 // itemsize
+    return (int(np.prod(lead)) * -(-sub // sub_tile) * sub_tile
+            * -(-lane // 128) * 128 * itemsize)
+
+
+def sweep_vmem_bytes(spec: StencilSpec, block, depth: int,
+                     itemsize: int) -> int:
+    """Upper bound on the VMEM a depth-``depth`` sweep kernel launch needs
+    for one ``block`` — (t0, *mid, nb, m, vl), or (1, m, vl) in 1-D: the
+    in/out blocks double-buffered, the window and carry scratch, and the
+    unrolled body's temporaries.  The last term, ``12 + 1.5·taps`` blocks
+    per unrolled step, bounds what Mosaic reported for the 3-D stencils
+    at 256³ and 512×256×1024 on v5e (t0 1-8, depth 1-4)."""
+    blk = _padded_bytes(block, itemsize)
+    row = blk // block[0]
+    scratch = depth * (blk + spec.r * row)
+    body = depth * blk * (12 + 3 * len(spec.taps) / 2)
+    return int(4 * blk + scratch + body)
+
+
+def _sweep_compiler_params(spec, block, depth, dtype
+                           ) -> pltpu.CompilerParams:
+    need = sweep_vmem_bytes(spec, block, depth, jnp.dtype(dtype).itemsize)
+    return pltpu.CompilerParams(vmem_limit_bytes=min(
+        VMEM_LIMIT_BYTES, max(SCOPED_VMEM_DEFAULT, need)))
+
+
+def _lane_rotate(x: jax.Array, shift: int) -> jax.Array:
+    """``jnp.roll(x, shift, axis=-1)`` as one TPU lane rotate.  Mosaic
+    rotates 32-bit data only, so narrower dtypes rotate widened (exact)."""
+    if x.dtype.itemsize >= 4:
+        return pltpu.roll(x, shift, x.ndim - 1)
+    return pltpu.roll(x.astype(jnp.float32), shift, x.ndim - 1
+                      ).astype(x.dtype)
 
 
 def _tap_sum_1d(spec: StencilSpec, ext: jax.Array, m: int) -> jax.Array:
@@ -136,6 +186,12 @@ def _kernel_1d(t_ref, o_ref, win_ref, vrl_ref, *, spec: StencilSpec,
         first_mask = (lanes == 0) & (rows < r)
         last_mask = (lanes == vl - 1) & (rows >= m - r)
 
+    # Assemble = blend + lane rotate, 2 ops per boundary vector (Fig. 3):
+    # the neighbor's carry lane is blended in where the rotate will wrap,
+    # so no lane index or scatter is needed (Mosaic lowers neither).
+    carry_lanes = lax.broadcasted_iota(jnp.int32, (r, vl), 1)
+    from_left = carry_lanes == vl - 1
+    from_right = carry_lanes == 0
     incoming = t_ref[0]                           # (m, vl)
     ws = [win_ref[i] for i in range(k)] + [incoming]
     new_vr = [None] * k
@@ -145,11 +201,10 @@ def _kernel_1d(t_ref, o_ref, win_ref, vrl_ref, *, spec: StencilSpec,
         new_vr[i] = vs[m - r:, :]                 # preserve pre-update tail
         left_tail = vrl_ref[i]                    # left block tail, same time
         right_head = ws[i + 1][:r, :]             # right block, just updated
-        # Assemble (blend + permute) — 2 ops per boundary vector (Fig. 3)
-        left_rows = jnp.roll(vs[m - r:, :], 1, axis=-1)
-        left_rows = left_rows.at[:, 0].set(left_tail[:, -1])
-        right_rows = jnp.roll(vs[:r, :], -1, axis=-1)
-        right_rows = right_rows.at[:, -1].set(right_head[:, 0])
+        left_rows = _lane_rotate(
+            jnp.where(from_left, left_tail, vs[m - r:, :]), 1)
+        right_rows = _lane_rotate(
+            jnp.where(from_right, right_head, vs[:r, :]), vl - 1)
         ext = jnp.concatenate([left_rows, vs, right_rows], axis=0)
         new = _tap_sum_1d(spec, ext, m)
         keep = (b < 0) | (b >= nb)
@@ -172,7 +227,7 @@ def _kernel_1d(t_ref, o_ref, win_ref, vrl_ref, *, spec: StencilSpec,
 
 
 def stencil1d_multistep(spec: StencilSpec, t: jax.Array, k: int,
-                        *, interpret: bool = True,
+                        *, interpret: bool,
                         edge_mask: bool = True) -> jax.Array:
     """t: (nb, m, vl) transpose-layout input → k-step update (dirichlet).
 
@@ -194,6 +249,8 @@ def stencil1d_multistep(spec: StencilSpec, t: jax.Array, k: int,
         out_shape=jax.ShapeDtypeStruct((nb, m, vl), t.dtype),
         scratch_shapes=[pltpu.VMEM((k, m, vl), t.dtype),
                         pltpu.VMEM((k, r, vl), t.dtype)],
+        compiler_params=_sweep_compiler_params(spec, (1, m, vl), k,
+                                               t.dtype),
         interpret=interpret,
     )(t)
 
@@ -241,7 +298,7 @@ def wrapped_sweep_index_maps(nblocks: int, pad: int, depth: int):
 
 
 def stencil1d_sweep_halo(spec: StencilSpec, t: jax.Array, k: int,
-                         halo: int, *, interpret: bool = True) -> jax.Array:
+                         halo: int, *, interpret: bool) -> jax.Array:
     """One k-step sweep on a halo-EXTENDED layout-resident (nb, m, vl)
     shard — the distributed engine's sweep kernel.
 
@@ -260,7 +317,7 @@ def stencil1d_sweep_halo(spec: StencilSpec, t: jax.Array, k: int,
 
 
 def stencil_nd_sweep_halo(spec: StencilSpec, t: jax.Array, k: int, t0: int,
-                          halo: int, *, interpret: bool = True
+                          halo: int, *, interpret: bool
                           ) -> jax.Array:
     """n-D analogue of :func:`stencil1d_sweep_halo`: one k-step sweep on
     a shard whose pipelined axis 0 carries ``halo`` exchanged ghost rows
@@ -274,7 +331,7 @@ def stencil_nd_sweep_halo(spec: StencilSpec, t: jax.Array, k: int, t0: int,
 
 
 def stencil1d_sweep_ttile(spec: StencilSpec, t: jax.Array, k: int,
-                          ttile: int = 1, *, interpret: bool = True
+                          ttile: int = 1, *, interpret: bool
                           ) -> jax.Array:
     """``ttile`` fully-periodic k-step sweeps — ``depth = ttile·k`` time
     steps — in ONE wrapped-grid launch on the layout-RESIDENT (nb, m, vl)
@@ -320,12 +377,14 @@ def stencil1d_sweep_ttile(spec: StencilSpec, t: jax.Array, k: int,
         out_shape=jax.ShapeDtypeStruct((nb, m, vl), t.dtype),
         scratch_shapes=[pltpu.VMEM((depth, m, vl), t.dtype),
                         pltpu.VMEM((depth, r, vl), t.dtype)],
+        compiler_params=_sweep_compiler_params(spec, (1, m, vl), depth,
+                                               t.dtype),
         interpret=interpret,
     )(t)
 
 
 def stencil1d_sweep_periodic(spec: StencilSpec, t: jax.Array, k: int,
-                             *, interpret: bool = True) -> jax.Array:
+                             *, interpret: bool) -> jax.Array:
     """One fully-periodic k-step sweep on the layout-RESIDENT (nb, m, vl)
     array — the ``ttile=1`` slice of :func:`stencil1d_sweep_ttile` (see
     there for the wrapped-grid construction)."""
@@ -396,7 +455,7 @@ def _kernel_nd(t_ref, o_ref, win_ref, vrl_ref, *, spec: StencilSpec,
 
 
 def stencil_nd_multistep(spec: StencilSpec, t: jax.Array, k: int, t0: int,
-                         *, interpret: bool = True,
+                         *, interpret: bool,
                          edge_mask: bool = True) -> jax.Array:
     """t: (n0, *mid, nb, m, vl) — transpose layout on the minor spatial dim.
 
@@ -425,12 +484,13 @@ def stencil_nd_multistep(spec: StencilSpec, t: jax.Array, k: int, t0: int,
         out_shape=jax.ShapeDtypeStruct(t.shape, t.dtype),
         scratch_shapes=[pltpu.VMEM((k,) + block, t.dtype),
                         pltpu.VMEM((k, r) + block[1:], t.dtype)],
+        compiler_params=_sweep_compiler_params(spec, block, k, t.dtype),
         interpret=interpret,
     )(t)
 
 
 def stencil_nd_sweep_ttile(spec: StencilSpec, t: jax.Array, k: int,
-                           ttile: int, t0: int, *, interpret: bool = True
+                           ttile: int, t0: int, *, interpret: bool
                            ) -> jax.Array:
     """``ttile`` fully-periodic k-step sweeps (``depth = ttile·k`` time
     steps) in ONE wrapped-grid launch on the layout-RESIDENT
@@ -468,12 +528,13 @@ def stencil_nd_sweep_ttile(spec: StencilSpec, t: jax.Array, k: int,
         out_shape=jax.ShapeDtypeStruct(t.shape, t.dtype),
         scratch_shapes=[pltpu.VMEM((depth,) + block, t.dtype),
                         pltpu.VMEM((depth, r) + block[1:], t.dtype)],
+        compiler_params=_sweep_compiler_params(spec, block, depth, t.dtype),
         interpret=interpret,
     )(t)
 
 
 def stencil_nd_sweep_periodic(spec: StencilSpec, t: jax.Array, k: int,
-                              t0: int, *, interpret: bool = True
+                              t0: int, *, interpret: bool
                               ) -> jax.Array:
     """One fully-periodic k-step sweep on the layout-RESIDENT
     (n0, *mid, nb, m, vl) array — the ``ttile=1`` slice of
@@ -569,7 +630,7 @@ def _kernel_transpose(x_ref, o_ref):
 
 
 def block_transpose(x: jax.Array, vl: int, m: int,
-                    *, interpret: bool = True, blocks_per_step: int = 8
+                    *, interpret: bool, blocks_per_step: int = 8
                     ) -> jax.Array:
     """(N,) → (nb, m, vl) transpose layout via an in-VMEM tile transpose.
 
@@ -595,7 +656,7 @@ def block_transpose(x: jax.Array, vl: int, m: int,
 
 
 def block_untranspose(t: jax.Array, vl: int, m: int,
-                      *, interpret: bool = True, blocks_per_step: int = 8
+                      *, interpret: bool, blocks_per_step: int = 8
                       ) -> jax.Array:
     nb = t.shape[0]
     g = max(1, min(blocks_per_step, nb))
@@ -632,7 +693,7 @@ def _kernel_naive_1d(x_ref, o_ref, *, spec: StencilSpec):
 
 
 def stencil1d_naive_onestep(spec: StencilSpec, x: jax.Array, vl: int = DEFAULT_VL,
-                            *, interpret: bool = True) -> jax.Array:
+                            *, interpret: bool) -> jax.Array:
     """One periodic step, natural layout: per-tap 128-lane rolls (baseline)."""
     n = x.shape[-1]
     assert n % vl == 0
@@ -667,7 +728,7 @@ def _tap_sum_nd(spec, ext, m):
 
 
 def stencil1d_transpose_onestep(spec: StencilSpec, t: jax.Array,
-                                *, interpret: bool = True) -> jax.Array:
+                                *, interpret: bool) -> jax.Array:
     """One periodic step in the transpose layout: per vector set, 2r
     assembled rows (lane-carry) + pure second-minor slices."""
     nb, m, vl = t.shape

@@ -156,7 +156,7 @@ def test_blockspec_full_coverage_revisits_not_flagged():
     spec = stencils.make("1d3p")
     from repro.kernels import stencil_kernels as sk
     closed = _traced(
-        lambda t: sk.stencil1d_sweep_ttile(spec, t, 2, 1),
+        lambda t: sk.stencil1d_sweep_ttile(spec, t, 2, 1, interpret=True),
         jax.ShapeDtypeStruct((4, 4, 8), F32))
     assert _kinds(closed) == set()
 
@@ -225,8 +225,9 @@ def test_seeded_bf16_accumulation():
 def test_seeded_whole_tile_ppermute():
     """Seeded violation 4: the lead-axis ring ships a whole t0-row tile
     pad instead of the exact d·r-row strip."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+
+    from repro.compat import shard_map
 
     mesh = jax.make_mesh((1,), ("d0",))
 
@@ -234,7 +235,7 @@ def test_seeded_whole_tile_ppermute():
         def inner(t):
             return lax.ppermute(t, "d0", [(0, 0)])
         return shard_map(inner, mesh=mesh, in_specs=P(),
-                         out_specs=P(), check_rep=False)(v)
+                         out_specs=P())(v)
 
     # strip rank = ndim+2 = 4 for 2d5p; shape[0]=4=t0 is the tile pad
     closed = _traced(prog, jax.ShapeDtypeStruct((4, 4, 4, 4), F32))

@@ -340,6 +340,29 @@ def test_unified_pool_measures_both_backends(cache_path):
     assert res2.cached and res2.plan.backend == "pallas"
 
 
+def test_failed_candidate_is_recorded(cache_path):
+    """A candidate that raises while measured (on the chip: a kernel
+    Mosaic refuses) is recorded with its error in the result and in the
+    cache record, and the search goes on without it."""
+    prob = StencilProblem("1d3p", (128,))
+
+    def pallas_refused(fn, plan):
+        if plan.backend == "pallas":
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+        return 1.0
+
+    res = autotune.tune(prob, cache_path=cache_path, timer=pallas_refused)
+    assert res.failures
+    assert all(p.backend == "pallas" for p, _ in res.failures)
+    assert all("Mosaic failed" in err for _, err in res.failures)
+    assert res.plan.backend != "pallas"
+    assert all(m["plan"]["backend"] != "pallas" for m in res.measurements)
+    with open(cache_path) as f:
+        record = json.load(f)["entries"][res.key]
+    assert [r["plan"] for r in record["failures"]] == [
+        autotune.plan_to_dict(p) for p, _ in res.failures]
+
+
 def test_backend_restriction_is_honored(cache_path):
     prob = StencilProblem("1d3p", (128,))
     res = autotune.tune(prob, backend="jnp", cache_path=cache_path,
@@ -692,11 +715,14 @@ def test_ttile_legality_gate():
                        decomp=(8,), ttile=4)
     assert autotune.ttile_plan_legal(spec, (256,), dist)     # nl=32 >= 8
     assert not autotune.ttile_plan_legal(spec, (32,), dist)  # nl=4 < 8
-    # VMEM window: a deep tile on a fat block blows the budget
-    fat = dataclasses.replace(base, vl=128, m=8, ttile=4)
-    assert not autotune.ttile_plan_legal(
-        spec, (1 << 20,), fat,
-        itemsize=autotune.TTILE_VMEM_BUDGET // (4 * 8 * (128 + 1)) + 1)
+    # VMEM: a deep tile on a fat 3-D block needs more than a kernel may
+    # claim; a thin tile at half the depth fits
+    spec3 = stencils.make("3d27p")
+    fat = StencilPlan(scheme="transpose", k=2, vl=128, m=1, t0=8,
+                      backend="pallas", sweep="resident", ttile=4)
+    assert not autotune.ttile_plan_legal(spec3, (256,) * 3, fat)
+    assert autotune.ttile_plan_legal(
+        spec3, (256,) * 3, dataclasses.replace(fat, t0=1, ttile=2))
 
 
 def test_pallas_pool_fans_out_along_ttile_axis():

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis import jaxpr_audit
 from repro.core import autotune, layouts, matrixize, stencils
@@ -232,6 +232,31 @@ def test_jaxpr_one_dot_general_per_chunk(steps, k, remainder, ttile):
                                       remainder, ttile))(x)
     c = _count_prims(closed)
     assert c["dot_general"] == len(chunks), (dict(c), chunks)
+
+
+@pytest.mark.parametrize("dtype,precision", [
+    (jnp.float32, jax.lax.Precision.HIGHEST),
+    (jnp.bfloat16, None),
+])
+def test_jaxpr_dot_general_precision(dtype, precision):
+    """f32 contractions ask for HIGHEST precision — the TPU's default
+    runs them as bf16 passes — while bf16 keeps the default, with f32
+    accumulation."""
+    spec = stencils.make("2d5p")
+    x = jnp.zeros((16, 64), dtype)
+    closed = jax.make_jaxpr(
+        lambda v: ops._sweep_mxu_impl(spec, v, 4, 2, 8, 8, "fused", 1))(x)
+    dots = [s.eqn for s in jaxpr_audit.walk(closed, enter_pallas=True)
+            if s.prim == "dot_general"]
+    assert dots
+    for eqn in dots:
+        got = eqn.params["precision"]
+        if precision is None:
+            assert got is None or all(
+                p in (None, jax.lax.Precision.DEFAULT) for p in got), got
+        else:
+            assert got == (precision, precision), got
+        assert eqn.params["preferred_element_type"] == jnp.float32
 
 
 # ---------------------------------------------------------------------------

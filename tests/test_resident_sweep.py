@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis import jaxpr_audit
 from repro.core import layouts, stencils

@@ -36,6 +36,7 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import stencils, vectorize, unroll_jam, tessellate
 
@@ -149,15 +150,16 @@ class StencilProblem:
         ``plan.remainder`` — single steps ("fused") or one shorter
         k=remainder block ("native") on the same backend.
         """
-        if isinstance(plan, str):
-            if plan == "auto":
-                from repro.core import autotune
-                plan = autotune.best_plan(self, steps=steps)
-            elif plan == "default":
-                plan = self.default_plan()
-            else:
-                raise ValueError(f"unknown plan {plan!r}; expected 'auto', "
-                                 f"'default' or a StencilPlan")
+        with TraceAnnotation("repro.run"):
+            if isinstance(plan, str):
+                with TraceAnnotation("repro.plan"):
+                    plan = self._resolve(plan, steps)
+            with TraceAnnotation("repro.dispatch"):
+                return self._execute(x, steps, plan)
+
+    def _execute(self, x: jax.Array, steps: int,
+                 plan: StencilPlan) -> jax.Array:
+        """Run ``steps`` under a resolved ``plan`` on its engine."""
         assert isinstance(plan, StencilPlan)
         if plan.ttile > 1 and not (
                 plan.backend in ("distributed", "mxu")
@@ -295,7 +297,8 @@ class StencilProblem:
         if fn is None:
             fn = jax.jit(jax.vmap(lambda v: self.run(v, steps, plan)))
             self._batched_fns[key] = fn
-        return fn(xb)
+        with TraceAnnotation("repro.dispatch"):
+            return fn(xb)
 
     def run_batched_parts(self, xs, steps: int,
                           plan: StencilPlan | str = "auto") -> list:
@@ -323,27 +326,33 @@ class StencilProblem:
             fn = jax.jit(
                 lambda parts: tuple(jax.vmap(run)(jnp.stack(parts))))
             self._batched_fns[key] = fn
-        return list(fn(tuple(xs)))
+        with TraceAnnotation("repro.dispatch"):
+            return list(fn(tuple(xs)))
 
     def _batched_plan(self, plan: StencilPlan | str,
                       steps: int) -> StencilPlan:
         """Resolve a plan argument for the batched entries and enforce
         the batch-invariance gate."""
         if isinstance(plan, str):
-            if plan == "auto":
-                from repro.core import autotune
-                plan = autotune.best_plan(self, steps=steps)
-            elif plan == "default":
-                plan = self.default_plan()
-            else:
-                raise ValueError(f"unknown plan {plan!r}; expected 'auto',"
-                                 f" 'default' or a StencilPlan")
+            with TraceAnnotation("repro.plan"):
+                plan = self._resolve(plan, steps)
         assert isinstance(plan, StencilPlan)
         from repro.core import autotune
         if not autotune.plan_batch_invariant(plan):
             raise ValueError(f"plan {plan} is not batch-invariant; "
                              "it cannot serve a batched run unchanged")
         return plan
+
+    def _resolve(self, plan: str, steps: int) -> StencilPlan:
+        """The plan a string names: ``"auto"`` (the tuner's, see
+        :meth:`run`) or ``"default"``."""
+        if plan == "auto":
+            from repro.core import autotune
+            return autotune.best_plan(self, steps=steps)
+        if plan == "default":
+            return self.default_plan()
+        raise ValueError(f"unknown plan {plan!r}; expected 'auto', "
+                         f"'default' or a StencilPlan")
 
     def _chunked(self, x: jax.Array, steps: int, k: int, step,
                  remainder: str = "fused") -> jax.Array:

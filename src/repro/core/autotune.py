@@ -130,6 +130,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import locked_json, stencils
 from repro.core.api import StencilPlan
@@ -1194,7 +1195,17 @@ def tune(problem, backend: str = "auto", steps: int | None = None,
                               n_candidates=hit.get("n_candidates", 0),
                               n_measured=hit.get("n_measured", 0),
                               cached=True)
+    with TraceAnnotation("repro.tune"):
+        return _search(problem, key, cache, backend, steps, timer,
+                       max_measure, measure_steps, calibrate_samples)
 
+
+def _search(problem, key: str, cache, backend: str, steps, timer,
+            max_measure: int, measure_steps: int | None,
+            calibrate_samples: bool) -> TuneResult:
+    """The miss path of :func:`tune`: enumerate, prune, audit and
+    measure the candidates, and persist the winner under ``key``."""
+    spec = problem.spec
     timer = timer or _default_timer
     cands = candidate_plans(spec, problem.shape, problem.dtype, backend,
                             steps=steps)

@@ -92,6 +92,10 @@ SCOPED_VMEM_DEFAULT = 16 << 20   # Mosaic's limit when a kernel sets none
 #: a smaller t0 is legal: the kernel body unrolls over the whole tile, so
 #: Mosaic's VMEM use and compile time grow with it
 ND_TILE_ELEMS = 1 << 16
+#: tags on every kernel launch (the custom call's ``kernel_metadata``, in
+#: the HLO and the device trace): a stencil sweep, or a layout transform
+SWEEP = {"repro": "sweep"}
+LAYOUT = {"repro": "layout"}
 
 
 def _ring_masks_np(vl: int, m: int, r: int):
@@ -252,6 +256,7 @@ def stencil1d_multistep(spec: StencilSpec, t: jax.Array, k: int,
         compiler_params=_sweep_compiler_params(spec, (1, m, vl), k,
                                                t.dtype),
         interpret=interpret,
+        metadata=SWEEP,
     )(t)
 
 
@@ -380,6 +385,7 @@ def stencil1d_sweep_ttile(spec: StencilSpec, t: jax.Array, k: int,
         compiler_params=_sweep_compiler_params(spec, (1, m, vl), depth,
                                                t.dtype),
         interpret=interpret,
+        metadata=SWEEP,
     )(t)
 
 
@@ -486,6 +492,7 @@ def stencil_nd_multistep(spec: StencilSpec, t: jax.Array, k: int, t0: int,
                         pltpu.VMEM((k, r) + block[1:], t.dtype)],
         compiler_params=_sweep_compiler_params(spec, block, k, t.dtype),
         interpret=interpret,
+        metadata=SWEEP,
     )(t)
 
 
@@ -530,6 +537,7 @@ def stencil_nd_sweep_ttile(spec: StencilSpec, t: jax.Array, k: int,
                         pltpu.VMEM((depth, r) + block[1:], t.dtype)],
         compiler_params=_sweep_compiler_params(spec, block, depth, t.dtype),
         interpret=interpret,
+        metadata=SWEEP,
     )(t)
 
 
@@ -652,6 +660,7 @@ def block_transpose(x: jax.Array, vl: int, m: int,
         out_specs=pl.BlockSpec((g, m, vl), lambda j: (j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, m, vl), x.dtype),
         interpret=interpret,
+        metadata=LAYOUT,
     )(xb)
 
 
@@ -669,6 +678,7 @@ def block_untranspose(t: jax.Array, vl: int, m: int,
         out_specs=pl.BlockSpec((g, vl, m), lambda j: (j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, vl, m), t.dtype),
         interpret=interpret,
+        metadata=LAYOUT,
     )(t)
     return out.reshape(nb * vl * m)
 
@@ -705,6 +715,7 @@ def stencil1d_naive_onestep(spec: StencilSpec, x: jax.Array, vl: int = DEFAULT_V
         out_specs=pl.BlockSpec(xb.shape, lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct(xb.shape, x.dtype),
         interpret=interpret,
+        metadata=SWEEP,
     )(xb)
     return out.reshape(n)
 
@@ -739,4 +750,5 @@ def stencil1d_transpose_onestep(spec: StencilSpec, t: jax.Array,
         out_specs=pl.BlockSpec(t.shape, lambda j: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(t.shape, t.dtype),
         interpret=interpret,
+        metadata=SWEEP,
     )(t)

@@ -11,6 +11,7 @@ TPU library, and where it cannot be described the tests skip.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,3 +90,28 @@ def test_mxu_sweep_compiles_at_highest_precision(one_chip):
         (3072, 3072), jnp.float32, one_chip)
     assert "HIGHEST" in lowered.as_text()
     assert compiled.as_text()
+
+
+_KERNEL_TAG = re.compile(
+    r'custom_call_target="tpu_custom_call"[^\n]*?'
+    r'kernel_metadata=\{\s*"repro"\s*:\s*"(\w+)"\s*\}')
+
+
+@pytest.mark.parametrize("name,shape,steps,tags", [
+    # one depth-4 sweep in the loop, one single-step remainder sweep
+    ("2d5p", (64, 1024), 9, ["sweep", "sweep"]),
+    # the Pallas block transposes in and out of the layout, one sweep
+    ("1d3p", (1 << 16,), 8, ["layout", "layout", "sweep"]),
+])
+def test_every_kernel_launch_is_tagged(one_chip, name, shape, steps, tags):
+    """Every Mosaic call of the resident run path carries its layer in
+    ``kernel_metadata``, which the device trace shows and the benchmark's
+    metrics select by."""
+    spec = stencils.make(name)
+    _, compiled = _compile(
+        lambda v: ops._sweep_periodic_impl(spec, v, steps, 4, None, None,
+                                           None, "fused", False),
+        shape, jnp.float32, one_chip)
+    text = compiled.as_text()
+    assert sorted(_KERNEL_TAG.findall(text)) == tags
+    assert text.count('custom_call_target="tpu_custom_call"') == len(tags)

@@ -228,9 +228,13 @@ def _sweep_periodic_impl(spec: StencilSpec, x: jax.Array, steps: int,
             spec, v, kk, tt, t0, interpret=interpret)
 
     def sweeps(v, kk, tt, n):
-        if n == 1:
-            return sweep(v, kk, tt)
-        return jax.lax.fori_loop(0, n, lambda _, u: sweep(u, kk, tt), v)
+        # two launches per loop body: the first writes a temporary and
+        # the second writes back into the buffer the carry came in, so
+        # XLA needs no copy of the field to return it (a Mosaic call
+        # cannot write the buffer it reads).  JAX peels an odd last
+        # launch; n <= 2 leaves no loop at all.
+        return jax.lax.fori_loop(0, n, lambda _, u: sweep(u, kk, tt), v,
+                                 unroll=2)
 
     for depth, n in chunks:
         # a depth-k·ttile chunk runs as the time-tiled kernel (one HBM

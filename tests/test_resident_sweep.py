@@ -80,6 +80,25 @@ def test_resident_parity_matrix(name, k, remainder):
                                    rtol=5e-5, atol=5e-5)
 
 
+@pytest.mark.parametrize("launches", [4, 5])
+@pytest.mark.parametrize("name", ["1d3p", "2d5p"])
+def test_resident_parity_long_chunk(name, launches):
+    """One chunk of 4 or 5 depth-2 launches — the sweep loop runs two
+    launches a body, and peels the odd last one — is bit-identical to
+    the per-sweep path."""
+    from repro.core.api import sweep_schedule
+    steps = 2 * launches
+    assert sweep_schedule(2, steps)[0] == [(2, launches)]
+    prob = StencilProblem(name, SHAPES[name])
+    x = _x(SHAPES[name], seed=10)
+    resident, roundtrip = _plans(name, 2, "fused")
+    got = np.asarray(prob.run(x, steps, resident))
+    ref = np.asarray(prob.run(x, steps, roundtrip))
+    np.testing.assert_array_equal(
+        got, ref, err_msg=f"{name} {launches} launches: resident != "
+        "per-sweep (must be bit-identical)")
+
+
 @pytest.mark.parametrize("name,shape,kw", [
     ("1d5p", (320,), dict(vl=8, m=4)),
     ("2d9p", (16, 64), dict(vl=8, m=4, t0=4)),

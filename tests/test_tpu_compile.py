@@ -10,6 +10,7 @@ TPU library, and where it cannot be described the tests skip.
 """
 from __future__ import annotations
 
+import math
 import os
 import re
 
@@ -98,10 +99,11 @@ _KERNEL_TAG = re.compile(
 
 
 @pytest.mark.parametrize("name,shape,steps,tags", [
-    # one depth-4 sweep in the loop, one single-step remainder sweep
-    ("2d5p", (64, 1024), 9, ["sweep", "sweep"]),
-    # the Pallas block transposes in and out of the layout, one sweep
-    ("1d3p", (1 << 16,), 8, ["layout", "layout", "sweep"]),
+    # two depth-4 sweeps (a chunk of two leaves no loop), one
+    # single-step remainder sweep
+    ("2d5p", (64, 1024), 9, ["sweep", "sweep", "sweep"]),
+    # the Pallas block transposes in and out of the layout, two sweeps
+    ("1d3p", (1 << 16,), 8, ["layout", "layout", "sweep", "sweep"]),
 ])
 def test_every_kernel_launch_is_tagged(one_chip, name, shape, steps, tags):
     """Every Mosaic call of the resident run path carries its layer in
@@ -115,3 +117,32 @@ def test_every_kernel_launch_is_tagged(one_chip, name, shape, steps, tags):
     text = compiled.as_text()
     assert sorted(_KERNEL_TAG.findall(text)) == tags
     assert text.count('custom_call_target="tpu_custom_call"') == len(tags)
+
+
+# a copy whose operand is a value of the loop's state: the carried field
+# copied into a fresh buffer before a launch
+_CARRY_COPY = re.compile(
+    r"= \w+\[([\d,]*)\]\S* copy\(%get-tuple-element")
+
+
+@pytest.mark.parametrize("launches", [2, 3, 4, 5])
+@pytest.mark.parametrize("name,shape", [
+    ("1d3p", (1 << 16,)),
+    ("2d5p", (64, 1024)),
+])
+def test_sweep_loop_copies_no_carried_field(one_chip, name, shape,
+                                            launches):
+    """One chunk of 2-5 depth-4 launches hands the field from launch to
+    launch without copying it: each launch writes the buffer the launch
+    before it read, so the loop needs no copy of its carry (an odd last
+    launch is peeled)."""
+    spec = stencils.make(name)
+    _, compiled = _compile(
+        lambda v: ops._sweep_periodic_impl(spec, v, 4 * launches, 4, None,
+                                           None, None, "fused", False),
+        shape, jnp.float32, one_chip)
+    text = compiled.as_text()
+    size = math.prod(shape)
+    carried = [dims for dims in _CARRY_COPY.findall(text)
+               if math.prod(int(d) for d in dims.split(",")) == size]
+    assert carried == [], carried

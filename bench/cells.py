@@ -4,7 +4,12 @@
 (each with its ``file``) and the metrics.  For a cell the harness then
 reads, each from a file of its own:
 
-* the configuration: ``configs[].file`` (grid, dtype, taps, stencil);
+* the configuration: ``configs[].file`` (grid, dtype, taps, stencil).
+  A configuration for more than one chip also has ``mesh``, the shards
+  per spatial axis (``[2, 2]``: the field split in halves along both
+  axes), whose product is the cell's ``chips``; the harness makes the
+  field in those shards and checks it shard by shard.  A configuration
+  without ``mesh`` is a one-device one;
 * the traffic mix: ``traffic/<traffic>.json`` beside this file;
 * the limits of the output comparison: ``limits/<workload>.json``;
 * each per-layer metric: a reader module ``metrics/<name>.py`` with a

@@ -39,9 +39,12 @@ def control_path(cell: cells.Cell, program: run.Setup) -> run.Setup:
     taps = work.taps_from_config(cell.config)
     steps = int(cell.traffic["steps_per_call"])
     low = CONTROL_DTYPE[cell.config["dtype"]]
+    mesh = run.cell_mesh(cell)
 
     def call(x):
-        return work.reference_steps(x, taps, steps, low)
+        if mesh is None:
+            return work.reference_steps(x, taps, steps, low)
+        return work.mesh_reference_steps(x, taps, steps, mesh, low)
     return run.Setup(call=call, plan=lambda: f"reference in {low}")
 
 
@@ -50,14 +53,15 @@ def readings(cell: cells.Cell, setup: run.Setup, seeds, seconds: float):
     cfg, tr = cell.config, cell.traffic
     taps = work.taps_from_config(cfg)
     steps = int(tr["steps_per_call"])
+    mesh = run.cell_mesh(cell)
     for seed in seeds:
-        x = run.make_input(cfg["shape"], cfg["dtype"], seed)
+        x = run.make_input(cfg["shape"], cfg["dtype"], seed, mesh)
         x = jax.block_until_ready(setup.call(x))
         calls, window_s, samples, x = run.window(
             setup.call, x, seconds, int(tr["check_calls"]),
             random.Random(seed))
         del x
-        worst, _, errs = run.check(samples, taps, steps, float("inf"))
+        worst, _, errs = run.check(samples, taps, steps, float("inf"), mesh)
         del samples
         yield {"seed": seed, "calls": len(calls), "rel_err": worst,
                "samples": errs}

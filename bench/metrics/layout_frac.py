@@ -4,10 +4,10 @@ The run driver moves the field into the kernels' (nb, m, vl) layout and
 back once per call.  On the n-D path these are XLA copies (and
 transposes) outside the sweep loop (``core/layouts.py``); on the 1-D path
 they are the Pallas block-transpose kernels, the custom calls that take
-the reshaped input (``%bitcast``) or the loop's result (``%while``).  The
-copies inside the sweep loop take a loop-carried value
-(``%get-tuple-element``) and are ``carry_copy_frac``'s, not these.
-Names as a TPU trace shows the HLO instructions today.
+the reshaped input (``%bitcast``) or the loop's result (``%while``).
+Copies of a loop-carried value (``%get-tuple-element``) are not the
+layout's and are left out.  Names as a TPU trace shows the HLO
+instructions today.
 """
 
 COPIES = ("copy|transpose", (), (r"\([^%]*%get-tuple-element",))

@@ -21,8 +21,9 @@ After the window, a sample of the window's calls, drawn from the seed,
 is checked against the plain reference in ``work.py``: each number
 compared is printed with its limit on the last lines of standard error
 and under ``checks`` in the result line, which is the last line of
-standard output.  Without a TPU, or with another chip count than the
-cell's, the run prints no result and exits 2.
+standard output.  Without a TPU, with another chip count than the
+cell's, or with a configuration whose ``mesh`` does not make that count,
+the run prints no result and exits 2.
 
 A cell's first run in a checkout tunes the plan (``plan="auto"`` finds no
 entry in the plan cache); it says so on standard error and in the result
@@ -96,12 +97,28 @@ def use_caches(root: Path) -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 
-def make_input(shape, dtype, seed: int):
+def make_input(shape, dtype, seed: int, mesh=None):
     """The field, made on the device in one jitted call from ``seed``
-    (any whole number: the low 32 bits key it, the rest is folded in)."""
+    (any whole number: the low 32 bits key it, the rest is folded in).
+    With ``mesh`` (``work.field_mesh``) it is made in shards, laid out by
+    ``work.field_sharding``: no device holds the whole grid, and the
+    values are those of the one-device field."""
     import jax
     key = jax.random.fold_in(jax.random.key(seed & 0xFFFFFFFF), seed >> 32)
-    return jax.jit(lambda k: jax.random.normal(k, tuple(shape), dtype))(key)
+    layout = {}
+    if mesh is not None:
+        from bench import work
+        layout["out_shardings"] = work.field_sharding(mesh)
+    return jax.jit(lambda k: jax.random.normal(k, tuple(shape), dtype),
+                   **layout)(key)
+
+
+def cell_mesh(cell: cells.Cell):
+    """The harness's mesh for a config with a ``mesh`` key, else None."""
+    if "mesh" not in cell.config:
+        return None
+    from bench import work
+    return work.field_mesh(cell.config["mesh"])
 
 
 class Setup(types.SimpleNamespace):
@@ -168,13 +185,22 @@ def window(call, x, seconds: float, n_samples: int, rng: random.Random):
     return calls, done - t_start, samples, x
 
 
-def check(samples, taps, steps: int, limit: float):
+def check(samples, taps, steps: int, limit: float, mesh=None):
     """Each sampled call's output against the plain reference applied to
-    the same input; the largest relative error is compared."""
+    the same input; the largest relative error is compared.  With
+    ``mesh`` both are first laid out on it, whatever layout the program
+    chose, and compared shard by shard."""
+    import jax
     from bench import work
     errs = []
     for i, xin, yout in samples:
-        ref = work.reference_steps(xin, taps, steps)
+        if mesh is None:
+            ref = work.reference_steps(xin, taps, steps)
+        else:
+            sharding = work.field_sharding(mesh)
+            xin = jax.device_put(xin, sharding)
+            yout = jax.device_put(yout, sharding)
+            ref = work.mesh_reference_steps(xin, taps, steps, mesh)
         errs.append(float(work.rel_err(yout, ref)))
         del ref
     worst = max(errs) if errs else math.inf
@@ -207,6 +233,13 @@ def main(argv=None, *, root: Path = ROOT, bench_dir: Path = BENCH,
     another timed path in the program's place."""
     args = parse(argv)
     cell = cells.load_cell(root, args.workload, bench_dir)
+    shards = cell.config.get("mesh", [1] * len(cell.config["shape"]))
+    if (len(shards) != len(cell.config["shape"])
+            or math.prod(shards) != cell.chips):
+        info(f"bench: {args.workload} needs {cell.chips} chip(s), but its "
+             f"config lays the {len(cell.config['shape'])}-D field out as "
+             f"mesh {cell.config.get('mesh')}")
+        return 2
     import jax
     devs = jax.devices()
     if require_chip:
@@ -235,12 +268,13 @@ def main(argv=None, *, root: Path = ROOT, bench_dir: Path = BENCH,
     taps = work.taps_from_config(cfg)
     steps = int(tr["steps_per_call"])
     shape = tuple(cfg["shape"])
+    mesh = cell_mesh(cell)
     setup = (path or program_path)(cell)
     plan_cache = getattr(setup, "plan_cache", None)
     if plan_cache == "miss":
         info("bench: plan cache miss: this run tunes the plan, and its "
              "setup_s is a tuning run's")
-    x = make_input(shape, cfg["dtype"], args.seed)
+    x = make_input(shape, cfg["dtype"], args.seed, mesh)
     x = jax.block_until_ready(setup.call(x))      # warm: tunes, compiles
     info(f"bench: plan {setup.plan()} (plan cache {plan_cache})")
     vpu = None
@@ -262,7 +296,8 @@ def main(argv=None, *, root: Path = ROOT, bench_dir: Path = BENCH,
     grid_bytes = work.points(shape) * itemsize
     info(f"bench: memory peak before the window {memory_peak(devs)} bytes "
          f"(set-up and the warm call); the check's samples hold up to "
-         f"{2 * n_samples * grid_bytes} bytes more in the window")
+         f"{2 * n_samples * grid_bytes // cell.chips} bytes more a chip in "
+         "the window")
     trace_dir = root / TRACE_DIR / args.workload
     if args.trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -316,7 +351,10 @@ def main(argv=None, *, root: Path = ROOT, bench_dir: Path = BENCH,
         breakdown = None
 
     limit = float(cell.limits["rel_err"]["limit"])
-    worst, failed, errs = check(samples, taps, steps, limit)
+    t_check = time.perf_counter()
+    worst, failed, errs = check(samples, taps, steps, limit, mesh)
+    info(f"bench: the check of {len(errs)} samples took "
+         f"{time.perf_counter() - t_check:.3f} s")
     del samples
     correct = math.isfinite(worst) and worst <= limit
     result.update(correct=correct, failed=failed, metrics=metrics,

@@ -17,8 +17,9 @@ def tree(tmp: Path, configs: dict, cells: dict, limit: float = 1e-5,
     """Write BENCHMARK.json, configs, one traffic file per mix and limits
     under ``tmp``; metrics and peaks are the real ones.
 
-    ``configs``: name -> (stencil, taps, shape); ``cells``: workload ->
-    (config, steps_per_call, planner, chips)."""
+    ``configs``: name -> (stencil, taps, shape) or (stencil, taps, shape,
+    mesh); ``cells``: workload -> (config, steps_per_call, planner,
+    chips)."""
     for sub in ("configs", "traffic", "limits"):
         (tmp / sub).mkdir(parents=True, exist_ok=True)
     shutil.copytree(BENCH / "metrics", tmp / "metrics", dirs_exist_ok=True)
@@ -28,11 +29,13 @@ def tree(tmp: Path, configs: dict, cells: dict, limit: float = 1e-5,
             "end_to_end": json.loads((BENCH.parent / "BENCHMARK.json")
                                      .read_text())["end_to_end"],
             "per_layer": per_layer or []}
-    for name, (stencil, taps, shape) in configs.items():
-        (tmp / "configs" / f"{name}.json").write_text(json.dumps(
-            {"name": name, "stencil": stencil, "taps": taps,
-             "shape": list(shape), "dtype": "float32",
-             "boundary": "periodic"}))
+    for name, (stencil, taps, shape, *mesh) in configs.items():
+        cfg = {"name": name, "stencil": stencil, "taps": taps,
+               "shape": list(shape), "dtype": "float32",
+               "boundary": "periodic"}
+        if mesh:
+            cfg["mesh"] = list(mesh[0])
+        (tmp / "configs" / f"{name}.json").write_text(json.dumps(cfg))
         spec["configs"].append({"name": name, "source": "test",
                                 "file": f"configs/{name}.json",
                                 "reduced": [], "why": "test"})

@@ -1,5 +1,6 @@
 """The harness finds every part of a cell by name, from files alone."""
 import json
+import math
 import types
 
 import pytest
@@ -49,6 +50,7 @@ def test_every_cell_of_the_benchmark_resolves():
     for w in spec["workloads"]:
         cell = cells.load_cell(ROOT, w["name"])
         assert cell.chips == w["chips"] == cell.config["chips"]
+        assert math.prod(cell.config.get("mesh", [1])) == cell.chips
         assert float(cell.limits["rel_err"]["limit"]) > 0
         for m in cell.per_layer:
             assert callable(cells.metric_reader(m["name"]).read)

@@ -81,10 +81,8 @@ def test_recorded_tpu_trace():
     assert r.busy_s == pytest.approx(0.9540, abs=1e-3)
     ctx = types.SimpleNamespace(trace=r)
     layout = cells.metric_reader("layout_frac").read(ctx)
-    carry = cells.metric_reader("carry_copy_frac").read(ctx)
     idle = cells.metric_reader("device_idle_frac").read(ctx)
     assert layout == pytest.approx(35.3, abs=0.1)
-    assert carry == pytest.approx(17.7, abs=0.1)
     assert idle == pytest.approx(6.43, abs=0.01)
     from bench.metrics import sweep_roofline as s
     kernels = r.layer_s(s.KINDS, s.PATTERNS, s.EXCLUDE)
